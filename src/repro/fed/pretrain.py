@@ -21,8 +21,9 @@ from repro.models import model as M
 from repro.models.config import ArchConfig
 from repro.optim import adamw, chain_clip
 from repro.optim.optimizers import apply_updates
+from repro.utils.runtime import checkout_root
 
-CACHE_DIR = os.environ.get("REPRO_CACHE", "/root/repo/.cache")
+CACHE_DIR = os.environ.get("REPRO_CACHE", str(checkout_root() / ".cache"))
 
 
 def _key(cfg: ArchConfig, steps: int, seed: int, family: str) -> str:

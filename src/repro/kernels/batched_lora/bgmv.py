@@ -23,8 +23,11 @@ per tenant); the effective magnitude forms inside the kernel:
 
     y[i] = scale · (((x[i] ⊙ A_mag) @ A_dir) ⊙ (B_mag + Δmag[idx[i]])) @ B_dir
 
-Here only the tiny (1, r) delta block is gathered per row; the shared
-factors load once and stay VMEM-resident across the whole grid.
+Here only the tiny (1, 1, r) delta block is gathered per row; the
+shared factors load once and stay VMEM-resident across the whole grid.
+The 1-D factors ride as (1, n) rows and the delta pool as
+(n_slots, 1, r), so every block's last two dims either equal the
+array's or tile by (8, 128) — Mosaic's layout rule.
 
 Heterogeneous pools: slots may hold adapters of different ranks, padded
 to the pool's r_max.  A second scalar-prefetch vector carries each row's
@@ -143,13 +146,13 @@ def _bgmv_mag_kernel(idx_ref, x_ref, adir_ref, amag_ref, bmag_ref, dmag_ref,
                      bdir_ref, o_ref, *, scale: float):
     del idx_ref
     x = x_ref[0]                                          # (bs, d_in)
-    xs = x * amag_ref[...][None, :].astype(x.dtype)
+    xs = x * amag_ref[...].astype(x.dtype)                # (1, d_in) row
     h = jax.lax.dot_general(
         xs, adir_ref[...].astype(x.dtype), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)               # (bs, r)
     # effective magnitude: shared B_mag + this row's raw ΔB_M — the same
     # single addition the merged lora_delta path performs
-    h = h * (bmag_ref[...] + dmag_ref[0])[None, :]
+    h = h * (bmag_ref[...] + dmag_ref[0])                 # (1, r) row
     y = jax.lax.dot_general(
         h.astype(x.dtype), bdir_ref[...].astype(x.dtype),
         (((1,), (0,)), ((), ())),
@@ -167,11 +170,11 @@ def _bgmv_mag_ranked_kernel(idx_ref, rank_ref, x_ref, adir_ref, amag_ref,
     del idx_ref
     i = pl.program_id(0)
     x = x_ref[0]                                          # (bs, d_in)
-    xs = x * amag_ref[...][None, :].astype(x.dtype)
+    xs = x * amag_ref[...].astype(x.dtype)
     h = jax.lax.dot_general(
         xs, adir_ref[...].astype(x.dtype), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)               # (bs, r)
-    h = h * (bmag_ref[...] + dmag_ref[0])[None, :]
+    h = h * (bmag_ref[...] + dmag_ref[0])
     keep = (jax.lax.broadcasted_iota(jnp.int32, h.shape, 1)
             < rank_ref[i])
     h = jnp.where(keep, h, 0.0)
@@ -205,10 +208,13 @@ def bgmv_mag_matmul(x, a_dir, a_mag, b_mag, dmag_pool, b_dir, idx,
             pl.BlockSpec((1, bs, d_in),
                          _imap(lambda i, s, idx_ref: (i, s, 0))),
             pl.BlockSpec((d_in, r), _imap(lambda i, s, idx_ref: (0, 0))),
-            pl.BlockSpec((d_in,), _imap(lambda i, s, idx_ref: (0,))),
-            pl.BlockSpec((r,), _imap(lambda i, s, idx_ref: (0,))),
-            pl.BlockSpec((1, r),
-                         _imap(lambda i, s, idx_ref: (idx_ref[i], 0))),
+            pl.BlockSpec((1, d_in), _imap(lambda i, s, idx_ref: (0, 0))),
+            pl.BlockSpec((1, r), _imap(lambda i, s, idx_ref: (0, 0))),
+            # the pool rides as (n_slots, 1, r): a (1, 1, r) block's last
+            # two dims equal the array's, which Mosaic's (8, 128) tiling
+            # rule needs — a (1, r) block of (n_slots, r) breaks it
+            pl.BlockSpec((1, 1, r),
+                         _imap(lambda i, s, idx_ref: (idx_ref[i], 0, 0))),
             pl.BlockSpec((r, d_out), _imap(lambda i, s, idx_ref: (0, 0))),
         ],
         out_specs=pl.BlockSpec((1, bs, d_out),
@@ -225,5 +231,6 @@ def bgmv_mag_matmul(x, a_dir, a_mag, b_mag, dmag_pool, b_dir, idx,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, d_out), x.dtype),
         interpret=interpret,
-    )(*args, x, a_dir, a_mag.astype(jnp.float32),
-      b_mag.astype(jnp.float32), dmag_pool.astype(jnp.float32), b_dir)
+    )(*args, x, a_dir, a_mag.astype(jnp.float32).reshape(1, d_in),
+      b_mag.astype(jnp.float32).reshape(1, r),
+      dmag_pool.astype(jnp.float32).reshape(-1, 1, r), b_dir)

@@ -1,37 +1,23 @@
 """Public dispatchers for the batched-LoRA (BGMV) kernels.
 
 ``bgmv`` / ``bgmv_mag`` route to the Pallas TPU kernel on TPU backends
-and to the vectorized einsum oracle elsewhere.  Unlike ``fused_dora``
-(validation-oriented), the CPU default here is the *oracle*, not
-interpret mode: these ops sit on the serving hot path and the Pallas
-interpreter is orders of magnitude slower than XLA.  Tests force the
-kernel body with ``impl="interpret"``.
+and to the vectorized einsum oracle elsewhere (``kernels.dispatch``).
+Tests force the kernel body with ``impl="interpret"``; ``impl="pallas"``
+off-TPU raises.
 
 Inputs accept (B, S, d_in) token blocks or (B, d_in) single-token decode
 rows; ``idx`` is the (B,) int32 pool-slot vector from the AdapterStore.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.batched_lora.bgmv import bgmv_matmul, bgmv_mag_matmul
 from repro.kernels.batched_lora.ref import bgmv_ref, bgmv_mag_ref
+from repro.kernels.dispatch import resolve_impl
 from repro.obs.tracing import named_scope
 
 _BS = 256                       # token-block size for the Pallas grid
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _resolve(impl):
-    if impl is None:
-        return "pallas" if _on_tpu() else "einsum"
-    if impl not in ("pallas", "interpret", "einsum"):
-        raise ValueError(f"unknown bgmv impl {impl!r}")
-    return impl
 
 
 def _pad_tokens(x):
@@ -51,7 +37,7 @@ def bgmv(x, a_pool, b_pool, idx, *, scale: float = 1.0, impl=None,
 
     ``ranks`` (L,) int32: heterogeneous pool — rank rows ≥ ranks[idx[i]]
     are masked out of row i (see bgmv.py)."""
-    impl = _resolve(impl)
+    impl = resolve_impl(impl, "bgmv")
     squeeze = x.ndim == 2
     if squeeze:
         x = x[:, None, :]
@@ -61,8 +47,7 @@ def bgmv(x, a_pool, b_pool, idx, *, scale: float = 1.0, impl=None,
         else:
             xp, S, bs = _pad_tokens(x)
             y = bgmv_matmul(xp, a_pool, b_pool, idx, ranks, scale=scale,
-                            bs=bs,
-                            interpret=(impl == "interpret") or not _on_tpu())
+                            bs=bs, interpret=impl == "interpret")
             y = y[:, :S]
     return y[:, 0] if squeeze else y
 
@@ -76,7 +61,7 @@ def bgmv_mag(x, a_dir, a_mag, b_mag, dmag_pool, b_dir, idx, *,
     ``ranks`` (L,) int32: heterogeneous pool — the magnitude product ≥
     the slot's rank is masked per row (shared b_mag rows included, so a
     rank-0 slot serves the bare backbone)."""
-    impl = _resolve(impl)
+    impl = resolve_impl(impl, "bgmv_mag")
     squeeze = x.ndim == 2
     if squeeze:
         x = x[:, None, :]
@@ -88,8 +73,7 @@ def bgmv_mag(x, a_dir, a_mag, b_mag, dmag_pool, b_dir, idx, *,
             xp, S, bs = _pad_tokens(x)
             y = bgmv_mag_matmul(xp, a_dir, a_mag, b_mag, dmag_pool, b_dir,
                                 idx, ranks, scale=scale, bs=bs,
-                                interpret=(impl == "interpret")
-                                or not _on_tpu())
+                                interpret=impl == "interpret")
             y = y[:, :S]
     return y[:, 0] if squeeze else y
 

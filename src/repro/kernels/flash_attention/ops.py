@@ -1,15 +1,16 @@
-"""jit'd public wrapper: (B,S,H,dh)-layout flash attention w/ GQA."""
+"""jit'd public wrapper: (B,S,H,dh)-layout flash attention w/ GQA.
+
+``interpret=None`` runs the compiled kernel on a TPU and the jnp oracle
+elsewhere; ``interpret=True`` runs the kernel body in the Pallas
+interpreter; ``interpret=False`` off-TPU raises (``kernels.dispatch``).
+"""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import impl_of_interpret, resolve_impl
 from repro.kernels.flash_attention.flash_attention import flash_attention_bhsd
-from repro.kernels.flash_attention.ref import attention_ref  # noqa: F401  (re-exported via repro.kernels)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.flash_attention.ref import attention_ref
 
 
 def _pick_block(S, pref):
@@ -23,10 +24,12 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     scale: float | None = None, bq: int = 512, bk: int = 512,
                     interpret: bool | None = None):
     """q (B,Sq,H,dh); k/v (B,Sk,K,dh) GQA → (B,Sq,H,dh)."""
+    impl = resolve_impl(impl_of_interpret(interpret), "flash_attention")
+    if impl == "einsum":
+        return attention_ref(q, k, v, causal=causal, window=window,
+                             scale=scale)
     B, Sq, H, dh = q.shape
     Sk, K = k.shape[1], k.shape[2]
-    if interpret is None:
-        interpret = not _on_tpu()
     if scale is None:
         scale = float(1.0 / jnp.sqrt(dh))
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, dh)
@@ -35,7 +38,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
     out = flash_attention_bhsd(
         qf, kf, vf, scale=scale, causal=causal, window=window,
         bq=_pick_block(Sq, bq), bk=_pick_block(Sk, bk),
-        q_offset=Sk - Sq, interpret=interpret)
+        q_offset=Sk - Sq, interpret=impl == "interpret")
     return out.reshape(B, H, Sq, dh).transpose(0, 2, 1, 3)
 
 

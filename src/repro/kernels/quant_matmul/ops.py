@@ -2,10 +2,8 @@
 ``quantize_backbone`` pass that produces the quantized param tree.
 
 ``quant_matmul`` routes to the Pallas TPU kernel on TPU backends and to
-the jnp oracle elsewhere.  Like ``batched_lora``, the CPU default is
-the *oracle*, not interpret mode: this op sits on the serving hot path
-and the Pallas interpreter is orders of magnitude slower than XLA.
-Tests force the kernel body with ``impl="interpret"``.
+the jnp oracle elsewhere (``kernels.dispatch``).  Tests force the kernel
+body with ``impl="interpret"``; ``impl="pallas"`` off-TPU raises.
 
 A quantized leaf is a dict ``{"kernel_q", "kernel_scale"}`` replacing
 the f32 ``{"kernel"}`` — ``models/layers.linear`` detects the shape and
@@ -19,6 +17,7 @@ import re
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import resolve_impl
 from repro.kernels.quant_matmul.quant_matmul import quant_matmul_kernel
 from repro.kernels.quant_matmul.ref import (dequantize, quant_matmul_ref,
                                             quantize_int4, quantize_int8,
@@ -36,24 +35,12 @@ _BN = 256                       # output-channel block size
 _PROJ_RX = re.compile(r"(?:^|/)(?:q|k|v|o|gate|up|down)_proj/kernel$")
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _resolve(impl):
-    if impl is None:
-        return "pallas" if _on_tpu() else "einsum"
-    if impl not in ("pallas", "interpret", "einsum"):
-        raise ValueError(f"unknown quant_matmul impl {impl!r}")
-    return impl
-
-
 def quant_matmul(x, q, scale, *, impl=None):
     """x (..., d_in) @ dequant(q, scale) → (..., d_out).
 
     ``q`` int8 (d_in, d_out) or packed-int4 uint8 (d_in/2, d_out);
     ``scale`` (G, d_out) f32 per-channel (G=1) or per-group scales."""
-    impl = _resolve(impl)
+    impl = resolve_impl(impl, "quant_matmul")
     with named_scope("kernels/quant_matmul"):
         if impl == "einsum":
             return quant_matmul_ref(x, q, scale)
@@ -67,9 +54,8 @@ def quant_matmul(x, q, scale, *, impl=None):
         if pn:                   # zero scales → padded columns dequant to 0
             q = jnp.pad(q, ((0, 0), (0, pn)))
             scale = jnp.pad(scale, ((0, 0), (0, pn)))
-        y = quant_matmul_kernel(
-            xm, q, scale, bm=bm, bn=bn,
-            interpret=(impl == "interpret") or not _on_tpu())
+        y = quant_matmul_kernel(xm, q, scale, bm=bm, bn=bn,
+                                interpret=impl == "interpret")
         return y[:M, :N].reshape(*lead, N)
 
 

@@ -8,15 +8,25 @@ the quantized block and its scales are DMA'd to VMEM, widened and
 scaled in-register, and fed straight to the MXU — a full-precision
 weight matrix never exists in HBM.
 
-Grid (M/bm, N/bn) with full-K tiles: each step streams one (K, bn)
-quantized weight block (the bytes win) against a resident (bm, K)
-activation block.  Layouts are ``ref.py``'s: int8 plain; int4 packed
-two-nibbles-per-byte along K with a +8 bias (unpacked by interleave in
-VMEM); scales (G, bn) per group of K/G input rows.
+Grid (M/bm, N/bn, Kq/tk), the K axis innermost: each step streams one
+(tk, bn) quantized weight block against (bm, tk) activation blocks and
+adds into an f32 (bm, bn) VMEM accumulator, written out on the last K
+step.  Tiling K keeps the working set bounded at any d_in: full-K tiles
+ran out of VMEM at the d_ff=11008 down-projection.
 
-VMEM working set (bm=bn=256, K=4096): x(256·4096·4) + q(4096·256) +
-w(4096·256·4) + out(256·256·4) ≈ 9.6 MB < 16 MB v5e VMEM at int8, and
-the packed-int4 block is half again smaller.
+Layouts are ``ref.py``'s: int8 plain; int4 packed two-nibbles-per-byte
+along K with a +8 bias — packed row p holds rows 2p (low nibble) and
+2p+1 (high nibble).  Rather than interleave the nibbles back into rows
+in VMEM, the int4 path takes the activations split into their even and
+odd columns (``x[:, 0::2]``, ``x[:, 1::2]``) and sums two matmuls over
+the packed rows.  Scales ride as (G, 1, N) so a K tile that spans ``ng``
+whole groups takes an (ng, 1, bn) block whose last two dims tile
+legally; K tiles never straddle a group boundary.
+
+VMEM working set (bm=bn=256, tk=512, f32 dequant): x(256·512·4) +
+q(512·256) + w(512·256·4) + acc(256·256·4) ≈ 1.4 MB, double-buffered
+inputs included well under the scoped VMEM limit.  Activations are
+widened to f32 before the MXU — correct, not yet tuned.
 """
 from __future__ import annotations
 
@@ -25,23 +35,53 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _qmm_kernel(x_ref, q_ref, s_ref, o_ref):
-    x = x_ref[...]                                        # (bm, K)
-    q = q_ref[...]                                        # (K|K/2, bn)
-    if q.dtype == jnp.uint8:                              # packed int4
-        lo = (q & 0xF).astype(jnp.int8) - 8
-        hi = (q >> 4).astype(jnp.int8) - 8
-        q = jnp.stack([lo, hi], axis=1).reshape(2 * q.shape[0], q.shape[1])
-    G = s_ref.shape[0]
-    K, bn = q.shape
-    w = (q.astype(jnp.float32).reshape(G, K // G, bn)
-         * s_ref[...][:, None, :]).reshape(K, bn)         # dequant in VMEM
-    y = jax.lax.dot_general(
-        x.astype(jnp.float32), w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)               # (bm, bn)
-    o_ref[...] = y.astype(o_ref.dtype)
+def _dequant(codes, s):
+    """(tk, bn) int32 codes × (ng, 1, bn) group scales → (tk, bn) f32."""
+    tk, bn = codes.shape
+    ng = s.shape[0]
+    w = codes.astype(jnp.float32)
+    if ng == 1:
+        return w * s[0]
+    return (w.reshape(ng, tk // ng, bn) * s).reshape(tk, bn)
+
+
+def _qmm_kernel(*refs, n_x: int):
+    x_refs, (q_ref, s_ref, o_ref, acc_ref) = refs[:n_x], refs[n_x:]
+    k = pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[...].astype(jnp.int32)                      # (tk, bn)
+    if n_x == 1:                                          # int8
+        codes = (q,)
+    else:                                                 # packed int4
+        codes = ((q & 0xF) - 8, (q >> 4) - 8)             # rows 2p, 2p+1
+    s = s_ref[...]                                        # (ng, 1, bn)
+    for x_ref, c in zip(x_refs, codes):
+        acc_ref[...] += jax.lax.dot_general(
+            x_ref[...].astype(jnp.float32), _dequant(c, s),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def pick_tk(kq: int, rows_per_group: int, target: int = 512) -> int:
+    """K tile: a divisor of ``kq`` that is a multiple of 128 (the
+    activation block's lane dim) and covers whole groups or sits inside
+    one — the largest such tile ≤ ``target``, else the smallest above
+    it, else the full K."""
+    legal = [t for t in range(128, kq + 1, 128)
+             if kq % t == 0 and (t % rows_per_group == 0
+                                 or rows_per_group % t == 0)]
+    small = [t for t in legal if t <= target]
+    return max(small) if small else min(legal, default=kq)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -52,20 +92,27 @@ def quant_matmul_kernel(x, q, scale, *, bm: int = 256, bn: int = 256,
     q int8 (d_in, d_out) or packed-int4 uint8 (d_in/2, d_out); scale
     (G, d_out) f32.  M and d_out must be block multiples — the ops.py
     dispatcher pads and slices."""
-    M, K = x.shape
-    N = q.shape[-1]
+    M, _ = x.shape
+    kq, N = q.shape                                       # kq: K or K/2
     assert M % bm == 0 and N % bn == 0, (M, N, bm, bn)
-    kq = q.shape[0]                                       # K (int8) or K/2
     G = scale.shape[0]
+    gq = kq // G                                          # q rows per group
+    tk = pick_tk(kq, gq)
+    ng = max(1, tk // gq)                                 # groups per tile
+    xs = (x,) if q.dtype == jnp.int8 else (x[:, 0::2], x[:, 1::2])
+    x_spec = pl.BlockSpec((bm, tk), lambda i, j, k: (i, k))
     return pl.pallas_call(
-        _qmm_kernel,
-        grid=(M // bm, N // bn),
-        in_specs=[
-            pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
-            pl.BlockSpec((kq, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((G, bn), lambda i, j: (0, j)),
+        functools.partial(_qmm_kernel, n_x=len(xs)),
+        grid=(M // bm, N // bn, kq // tk),
+        in_specs=[x_spec] * len(xs) + [
+            pl.BlockSpec((tk, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec((ng, 1, bn),
+                         lambda i, j, k: (k * tk // (gq * ng), 0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, q, scale)
+    )(*xs, q, scale.reshape(G, 1, N))

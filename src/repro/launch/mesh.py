@@ -7,13 +7,11 @@ dryrun.py sets XLA_FLAGS for 512 placeholder devices before any import.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:
-    from jax.sharding import AxisType
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}
-except ImportError:      # older jax: no explicit axis types — meshes are
-    _AXIS_KW = lambda n: {}          # Auto by default, importing must work
-    AxisType = None
+
+def _auto(n: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,10 +30,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = int(np.prod(shape))
     devs = jax.devices()
     if len(devs) == n:
-        return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+        return jax.make_mesh(shape, axes, **_auto(len(axes)))
     assert len(devs) >= n, f"need {n} devices, have {len(devs)}"
     return Mesh(np.asarray(devs[:n]).reshape(shape), axes,
-                **_AXIS_KW(len(axes)))
+                **_auto(len(axes)))
 
 
 def make_debug_mesh(n_data: int = 4, n_model: int = 2, *,
@@ -43,19 +41,18 @@ def make_debug_mesh(n_data: int = 4, n_model: int = 2, *,
     """Small mesh for CI-scale distributed tests (8 host devices)."""
     if multi_pod:
         return jax.make_mesh((2, n_data // 2, n_model),
-                             ("pod", "data", "model"), **_AXIS_KW(3))
+                             ("pod", "data", "model"), **_auto(3))
     return jax.make_mesh((n_data, n_model), ("data", "model"),
-                         **_AXIS_KW(2))
+                         **_auto(2))
 
 
 def make_client_mesh(n_clients: int):
     """Data-only mesh: one shard per federated client, no tensor-parallel
     axis.  Because every mesh axis is a client axis, the federated train
-    step's shard_map region is *fully* manual over it — the layout that
-    runs on every jax this repo supports (partial-auto shard_map needs
-    ``jax.shard_map``; see ``shard_map_compat``).  The CI --dist lane and
-    the 8-virtual-device parity sweep run on this mesh."""
-    return jax.make_mesh((n_clients,), ("data",), **_AXIS_KW(1))
+    step's ``jax.shard_map`` region is *fully* manual over it.  The CI
+    --dist lane, the 8-virtual-device parity sweep and the chip smoke run
+    on this mesh."""
+    return jax.make_mesh((n_clients,), ("data",), **_auto(1))
 
 
 def data_axes(mesh) -> tuple[str, ...]:
@@ -69,32 +66,3 @@ def dp_size(mesh) -> int:
         out *= mesh.shape[a]
     return out
 
-
-# ---------------------------------------------------------------------------
-# jax-version compat: the production train step targets jax.shard_map /
-# jax.set_mesh (jax >= 0.6); this container ships 0.4.x, where shard_map
-# lives in jax.experimental and partial-auto (manual data axes + auto
-# model axis) aborts in the SPMD partitioner.  Fully-manual regions work
-# on both — so data-only meshes (make_client_mesh) run everywhere, and
-# meshes with a model axis require the newer API.
-# ---------------------------------------------------------------------------
-
-
-def shard_map_compat(f, mesh, *, in_specs, out_specs, manual_axes):
-    """``jax.shard_map`` manual over ``manual_axes`` (auto elsewhere),
-    falling back to ``jax.experimental.shard_map`` on older jax — where
-    only fully-manual meshes are supported (partial-auto crashes XLA's
-    partitioner on 0.4.x)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(manual_axes), check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    if auto:
-        raise NotImplementedError(
-            f"partial-auto shard_map (auto axes {sorted(auto)}) requires "
-            "jax.shard_map (jax >= 0.6); on this jax use a data-only mesh "
-            "(make_client_mesh) so the region is fully manual")
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)

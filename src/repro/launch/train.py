@@ -74,7 +74,7 @@ from repro import obs
 from repro.core import aggregation as fedagg
 from repro.core import peft
 from repro.core.methods import get_method
-from repro.launch.mesh import data_axes, dp_size, shard_map_compat
+from repro.launch.mesh import data_axes, dp_size
 from repro.models import model as M
 from repro.models.config import ArchConfig
 from repro.optim import adamw, masked
@@ -585,14 +585,14 @@ def make_fed_pipeline_step(cfg: ArchConfig, mesh,
                    else jnp.asarray(staleness, jnp.float32))
         scale_c = (jnp.ones((dp,), jnp.float32) if update_scale is None
                    else jnp.asarray(update_scale, jnp.float32))
-        body = shard_map_compat(
+        body = jax.shard_map(
             partial(round_body, use_rng=use_rng, use_faults=use_faults),
-            mesh,
+            mesh=mesh,
             in_specs=(base_manual_specs(base, cfg), ad_spec, ost_spec, P(),
                       batch_spec_of(batch), ad_spec, w_spec, w_spec,
                       w_spec, w_spec, cov_spec, P()),
             out_specs=(ad_spec, ost_spec, agg_spec, P()),
-            manual_axes=daxes,
+            axis_names=set(daxes), check_vma=False,
         )
         return body(base, adapters, opt_state, step, batch, anchor,
                     w_c, part_c, stale_c, scale_c, covers_c, rng)
@@ -633,13 +633,13 @@ def make_fed_pipeline_step(cfg: ArchConfig, mesh,
         use_rng = rng is not None
         if not use_rng:
             rng = jnp.zeros((2,), jnp.uint32)   # placeholder, never consumed
-        body = shard_map_compat(
+        body = jax.shard_map(
             partial(global_body, use_rng=use_rng),
-            mesh,
+            mesh=mesh,
             in_specs=(base_manual_specs(base, cfg), agg_spec, ad_spec, P(),
                       cov_spec, P()),
             out_specs=(agg_spec, ad_spec, P()),
-            manual_axes=daxes,
+            axis_names=set(daxes), check_vma=False,
         )
         return body(base, aggregated, adapters, server_batch, covers_c, rng)
 
@@ -662,13 +662,13 @@ def make_fed_pipeline_step(cfg: ArchConfig, mesh,
         use_rng = rng is not None
         if not use_rng:
             rng = jnp.zeros((2,), jnp.uint32)   # placeholder, never consumed
-        body = shard_map_compat(
+        body = jax.shard_map(
             partial(personal_body, use_rng=use_rng),
-            mesh,
+            mesh=mesh,
             in_specs=(base_manual_specs(base, cfg), ad_spec,
                       batch_spec_of(batch), cov_spec, P()),
             out_specs=(ad_spec, P()),
-            manual_axes=daxes,
+            axis_names=set(daxes), check_vma=False,
         )
         return body(base, adapters, batch, covers_c, rng)
 

@@ -11,8 +11,8 @@ A rule implements either or both hooks:
 
 The jit-reachability index is module-local on purpose: a function is
 "jit-reachable" when it is (a) passed to ``jax.jit`` / ``jax.vmap`` /
-``jax.pmap`` / ``shard_map`` / ``shard_map_compat`` / ``jax.lax.scan``
-(possibly through ``functools.partial`` or ``obs.annotate(...)(...)``),
+``jax.pmap`` / ``jax.shard_map`` / ``jax.lax.scan`` (possibly through
+``functools.partial`` or ``obs.annotate(...)(...)``),
 (b) decorated with a jit wrapper, or (c) referenced by name from the
 body of another jit-reachable function in the same module.  Cross-module
 tracing (``model.forward`` called from a jitted round body) is out of
@@ -113,7 +113,7 @@ def func_operand(node) -> Optional[ast.Name]:
     return None
 
 
-_JIT_WRAPPERS = ("jit", "vmap", "pmap", "shard_map", "shard_map_compat")
+_JIT_WRAPPERS = ("jit", "vmap", "pmap", "shard_map")
 FunctionNode = (ast.FunctionDef, ast.AsyncFunctionDef)
 
 

@@ -64,7 +64,7 @@ class ArchConfig:
     lora_dropout: float = 0.1
     lora_targets: Sequence[str] = ("q_proj", "v_proj")
     use_fused_dora: bool = False  # fuse base+adapter matmul via the Pallas
-                                  # kernel (interpret off-TPU); forward-only
+                                  # kernel (jnp oracle off-TPU); forward-only
                                   # — the kernel has no VJP, so keep False
                                   # for training
     # --- serving-time weight-only quantization ---

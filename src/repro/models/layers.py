@@ -169,7 +169,7 @@ def linear(p: Params, x, *, lora_scale: float = 0.0, dropout_rng=None,
         # (pooled per-row routing outranks the fused single-adapter path:
         # taking the fused branch here would silently serve every tenant
         # the shared adapter)
-        # fused base+adapter matmul (Pallas; interpret mode off-TPU).
+        # fused base+adapter matmul (Pallas on TPU, jnp oracle elsewhere).
         # Forward/serving only: pallas_call has no VJP here, so training
         # paths keep fused=False.
         from repro.kernels import fused_dora

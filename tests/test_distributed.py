@@ -1,14 +1,9 @@
 """Multi-device tests (8 host devices via subprocess — XLA locks device
 count at first init, so these run in their own interpreter).
 
-Two compatibility tiers (see launch/mesh.shard_map_compat):
-
-  · data-only client meshes (make_client_mesh) run the *fully manual*
-    shard_map region — available on every supported jax, including the
-    0.4.x this container ships (jax.experimental.shard_map);
-  · meshes with a tensor-parallel 'model' axis need partial-auto
-    shard_map (jax.shard_map / jax.set_mesh, jax >= 0.6) — those tests
-    skip on older jax.
+Client meshes (make_client_mesh) run the fully manual ``jax.shard_map``
+region; meshes with a tensor-parallel 'model' axis run it partial-auto
+(manual data axes, auto 'model').
 
 The collective-parity sweeps are the acceptance gate for the
 distributed aggregation engine: for every method in the registry, one
@@ -19,26 +14,19 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 pytestmark = pytest.mark.dist
-
-# Partial-auto shard_map (manual data axes + auto 'model' axis) targets
-# the jax.shard_map / jax.set_mesh APIs; on older jax (this container
-# ships 0.4.x) those do not exist and the model-parallel tests cannot run.
-NEEDS_PARTIAL_AUTO = pytest.mark.skipif(
-    not (hasattr(jax, "shard_map") and hasattr(jax, "set_mesh")),
-    reason="partial-auto shard_map requires jax.shard_map/jax.set_mesh "
-           "(newer jax than installed)")
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _run(snippet: str, timeout=900):
+    # the child runs on 8 virtual CPU devices and never on an accelerator:
+    # a chip belongs to one process, and the parent may hold it
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=SRC)
+               JAX_PLATFORMS="cpu", PYTHONPATH=SRC)
     r = subprocess.run([sys.executable, "-c", snippet], env=env,
                        capture_output=True, text=True, timeout=timeout)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-3000:]}"
@@ -117,6 +105,14 @@ def compare(name, prod, ref):
                                        err_msg=f"{name}:{p}")
             close = np.isclose(prod[p], ref[p], rtol=2e-4, atol=2e-5)
             assert close.mean() > 0.99, (name, p, float(close.mean()))
+        elif name == "adapter":
+            # Houlsby adapters amplify rounding: nudging the oracle's own
+            # initial adapters by one ulp moves one adapter_down element
+            # by 3.0e-5 after two pipeline iterations, the same size as
+            # the engine-vs-oracle gap (3.4e-5).  That is reduction order,
+            # not engine math; atol is twice the oracle's own sensitivity
+            np.testing.assert_allclose(prod[p], ref[p], rtol=2e-4, atol=6e-5,
+                                       err_msg=f"{name}:{p}")
         else:
             np.testing.assert_allclose(prod[p], ref[p], rtol=2e-4, atol=2e-5,
                                        err_msg=f"{name}:{p}")
@@ -524,11 +520,10 @@ def test_fed_train_step_rejects_bad_fleets():
 
 
 # ---------------------------------------------------------------------------
-# model-parallel tests (partial-auto shard_map; jax >= 0.6 only)
+# model-parallel tests (partial-auto shard_map over a 'model' axis)
 # ---------------------------------------------------------------------------
 
 
-@NEEDS_PARTIAL_AUTO
 def test_fed_train_step_dense_and_moe_debug_mesh():
     out = _run("""
 import jax, jax.numpy as jnp
@@ -564,7 +559,6 @@ for fam_kw in [dict(family="dense"), dict(family="moe", n_experts=4, top_k=2)]:
     assert out.count("OK") == 2
 
 
-@NEEDS_PARTIAL_AUTO
 def test_moe_ep_matches_local_math():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
@@ -595,7 +589,6 @@ print("OK")
 """)
 
 
-@NEEDS_PARTIAL_AUTO
 def test_dryrun_tiny_mesh_smoke():
     """The dry-run machinery end-to-end on a small mesh with a reduced
     arch — exercises lower+compile+analysis without the 512-dev cost."""

@@ -1,4 +1,6 @@
 """Per-kernel shape/dtype sweeps vs pure-jnp oracles (interpret mode)."""
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -26,7 +28,7 @@ def test_fused_dora_sweep(M, K, N, r, dt):
     bm = jnp.asarray(RNG.uniform(0.1, 0.5, size=(r,)), jnp.float32)
     dad = jnp.asarray(RNG.normal(size=(K, r)) * 0.05, jnp.float32)
     dbm = jnp.asarray(RNG.normal(size=(r,)) * 0.05, jnp.float32)
-    y = fused_dora(x, w0, ad, am, bd, bm, dad, dbm, scale=2.0)
+    y = fused_dora(x, w0, ad, am, bd, bm, dad, dbm, scale=2.0, interpret=True)
     yr = fused_dora_ref(x, w0, ad, am, bd, bm, dad, dbm, 2.0)
     scale = float(jnp.max(jnp.abs(yr))) + 1e-6
     err = float(jnp.max(jnp.abs(y.astype(jnp.float32) - yr.astype(jnp.float32))))
@@ -34,7 +36,16 @@ def test_fused_dora_sweep(M, K, N, r, dt):
     assert err / scale < tol, (err, scale)
 
 
-def test_linear_fused_flag_matches_jnp_path():
+@pytest.fixture
+def fused_kernel_body(monkeypatch):
+    """Route layers.linear's fused path through the Pallas kernel body
+    (interpreter): off-TPU the wrapper's default is the jnp oracle."""
+    import repro.kernels
+    monkeypatch.setattr(repro.kernels, "fused_dora",
+                        functools.partial(fused_dora, interpret=True))
+
+
+def test_linear_fused_flag_matches_jnp_path(fused_kernel_body):
     """layers.linear(fused=True) (ArchConfig.use_fused_dora) must agree
     with the plain jnp base+lora_delta path on decomposed adapters."""
     from repro.models.layers import linear
@@ -60,7 +71,7 @@ def test_linear_fused_flag_matches_jnp_path():
         np.asarray(linear(p_raw, x, lora_scale=2.0, fused=False)))
 
 
-def test_model_forward_with_use_fused_dora_flag():
+def test_model_forward_with_use_fused_dora_flag(fused_kernel_body):
     """End-to-end: ArchConfig.use_fused_dora routes the decomposed-LoRA
     projections through the fused kernel with matching loss."""
     import dataclasses
@@ -95,7 +106,7 @@ def test_fused_dora_batched_input():
     am = jnp.ones((128,), jnp.float32)
     bd = jnp.asarray(RNG.normal(size=(8, 128)), jnp.float32)
     bm = jnp.ones((8,), jnp.float32)
-    y = fused_dora(x, w0, ad, am, bd, bm)
+    y = fused_dora(x, w0, ad, am, bd, bm, interpret=True)
     assert y.shape == (2, 64, 128)
 
 
@@ -112,7 +123,8 @@ def test_flash_attention_sweep(case, dt):
     q = jnp.asarray(RNG.normal(size=(c["B"], c["Sq"], c["H"], c["dh"])), dt)
     k = jnp.asarray(RNG.normal(size=(c["B"], c["Sk"], c["K"], c["dh"])), dt)
     v = jnp.asarray(RNG.normal(size=(c["B"], c["Sk"], c["K"], c["dh"])), dt)
-    y = flash_attention(q, k, v, causal=c["causal"], window=c["window"])
+    y = flash_attention(q, k, v, causal=c["causal"], window=c["window"],
+                        interpret=True)
     yr = attention_ref(q, k, v, causal=c["causal"], window=c["window"])
     err = float(jnp.max(jnp.abs(y.astype(jnp.float32) - yr.astype(jnp.float32))))
     assert err < (2e-2 if dt == jnp.bfloat16 else 2e-5)
@@ -130,7 +142,7 @@ def test_ssd_scan_sweep(b, S, H, G, P, N, Q):
     A_log = jnp.asarray(np.log(RNG.uniform(0.5, 4.0, size=(H,))), jnp.float32)
     B = jnp.asarray(RNG.normal(size=(b, S, G, N)), jnp.float32)
     C = jnp.asarray(RNG.normal(size=(b, S, G, N)), jnp.float32)
-    y_k, st_k = ssd_scan(x, dt, A_log, B, C, chunk=Q)
+    y_k, st_k = ssd_scan(x, dt, A_log, B, C, chunk=Q, interpret=True)
     y_n, st_n = ssd_naive(x, dt, A_log, B, C)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_n),
                                rtol=1e-3, atol=1e-4)
@@ -149,3 +161,40 @@ def test_ssd_model_ref_matches_naive():
     y_n, st_n = ssd_naive(x, dt, A_log, B, C)
     np.testing.assert_allclose(np.asarray(y_r), np.asarray(y_n),
                                rtol=1e-3, atol=1e-4)
+
+
+def _explicit_compiled_calls():
+    from repro.kernels import bgmv, bgmv_mag, quant_matmul, quantize_int8
+    x3 = jnp.ones((2, 4, 16), jnp.float32)
+    pool_a, pool_b = jnp.ones((3, 16, 4)), jnp.ones((3, 4, 16))
+    idx = jnp.zeros((2,), jnp.int32)
+    q, s = quantize_int8(jnp.ones((16, 8), jnp.float32))
+    qkv = jnp.ones((1, 128, 2, 64), jnp.float32)
+    ssd_in = (jnp.ones((1, 32, 2, 8)), jnp.full((1, 32, 2), 0.1),
+              jnp.zeros((2,)), jnp.ones((1, 32, 1, 4)), jnp.ones((1, 32, 1, 4)))
+    w0 = jnp.ones((128, 128), jnp.float32)
+    return {
+        "bgmv": lambda: bgmv(x3, pool_a, pool_b, idx, impl="pallas"),
+        "bgmv_mag": lambda: bgmv_mag(
+            x3, jnp.ones((16, 4)), jnp.ones((16,)), jnp.ones((4,)),
+            jnp.ones((3, 4)), jnp.ones((4, 16)), idx, impl="pallas"),
+        "quant_matmul": lambda: quant_matmul(x3, q, s, impl="pallas"),
+        "flash_attention": lambda: flash_attention(qkv, qkv, qkv,
+                                                   interpret=False),
+        "ssd_scan": lambda: ssd_scan(*ssd_in, chunk=16, interpret=False),
+        "fused_dora": lambda: fused_dora(
+            jnp.ones((128, 128)), w0, jnp.ones((128, 4)), jnp.ones((128,)),
+            jnp.ones((4, 128)), jnp.ones((4,)), interpret=False),
+    }
+
+
+@pytest.mark.parametrize("op", ["bgmv", "bgmv_mag", "quant_matmul",
+                                "flash_attention", "ssd_scan", "fused_dora"])
+def test_explicit_compiled_kernel_refuses_non_tpu(op, monkeypatch):
+    """Asking for the compiled kernel off-TPU raises — it never quietly
+    runs the Pallas interpreter in its place.  The backend probe is
+    pinned to "not a TPU" so the check holds on any host."""
+    from repro.kernels import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: False)
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        _explicit_compiled_calls()[op]()

@@ -168,9 +168,12 @@ def test_quantize_backbone_leaf_coverage():
 
 def test_quantized_forward_drift_bounded():
     """End-to-end forward through the quantized backbone stays within
-    the codec's noise band of the f32 model (int8 ≪ int4)."""
+    the codec's noise band of the f32 model (int8 ≪ int4).  The tokens
+    come from a generator of the test's own, so the drift measured does
+    not depend on which tests drew from the module's RNG before."""
     base = M.init_params(jax.random.PRNGKey(0), CFG)
-    batch = {"tokens": jnp.asarray(RNG.integers(5, 64, size=(2, 16)),
+    rng = np.random.default_rng(7)
+    batch = {"tokens": jnp.asarray(rng.integers(5, 64, size=(2, 16)),
                                    jnp.int32)}
     ref = np.asarray(M.forward(base, batch, CFG)[0])
     drift = {}
