@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation runs on the device,
+averaged over the chips, in %."""
+
+
+def read(ctx):
+    return 100.0 * (1.0 - ctx["busy_s"] / ctx["window_s"])
