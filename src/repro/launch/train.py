@@ -61,7 +61,6 @@ from __future__ import annotations
 
 import dataclasses
 import re
-import time
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -77,6 +76,7 @@ from repro.core.methods import get_method
 from repro.launch.mesh import data_axes, dp_size
 from repro.models import model as M
 from repro.models.config import ArchConfig
+from repro.obs.tracing import named_scope
 from repro.optim import adamw, masked
 from repro.optim.optimizers import apply_updates, clip_by_global_norm
 from repro.utils import pytree as pt
@@ -200,34 +200,40 @@ class FedPipeline:
         ``step + local_steps``) into the next iteration — for prox
         methods the anchor is the post-round rebroadcast, which stages
         2/3 must not disturb (mirrors ``FedSim._round_ref``)."""
-        enabled = self.telemetry and obs.enabled()
-        t0 = time.perf_counter() if enabled else 0.0
-        adapters, opt_state, agg, met1 = self.round_step(
-            base, adapters, opt_state, step, batch, prox_anchor, rng)
-        if enabled:
-            jax.block_until_ready(adapters)
-            t1 = time.perf_counter()
+        name = self.method.name
+        # each stage in a program span (a named range on the trace clock);
+        # with telemetry on, the span blocks on its stage so its time
+        # covers the device work
+        timed = obs.enabled()
+        with obs.span("fed/round", method=name) as s1:
+            adapters, opt_state, agg, met1 = self.round_step(
+                base, adapters, opt_state, step, batch, prox_anchor, rng)
+            if timed:
+                jax.block_until_ready(adapters)
         anchor = adapters if self.method.prox else None
-        agg, adapters, met2 = self.global_step(base, agg, adapters,
-                                               server_batch, global_rng)
-        if enabled:
-            jax.block_until_ready(adapters)
-            t2 = time.perf_counter()
-        adapters, met3 = self.personal_step(base, adapters, personal_batch,
-                                            personal_rng)
-        if enabled:
-            jax.block_until_ready(adapters)
-            t3 = time.perf_counter()
+        with obs.span("fed/stage2_global", method=name) as s2:
+            agg, adapters, met2 = self.global_step(base, agg, adapters,
+                                                   server_batch, global_rng)
+            if timed:
+                jax.block_until_ready(adapters)
+        with obs.span("fed/stage3_personalize", method=name) as s3:
+            adapters, met3 = self.personal_step(base, adapters,
+                                                personal_batch, personal_rng)
+            if timed:
+                jax.block_until_ready(adapters)
+        if timed and self.telemetry:
             self._emit_round_event(step, met1, met2, met3,
-                                   (t1 - t0, t2 - t1, t3 - t2, t3 - t0))
+                                   (s1.seconds, s2.seconds, s3.seconds))
         return adapters, opt_state, agg, anchor, {
             "round": met1, "global": met2, "personal": met3}
 
     def _emit_round_event(self, step, met1, met2, met3, wall):
         """Host epilogue: feed the round program's replicated per-client
-        metric leaves into the global telemetry sink."""
+        metric leaves into the global telemetry sink.  ``wall``: the three
+        stage spans' seconds."""
         name = self.method.name
-        dt_round, dt_global, dt_personal, total = wall
+        dt_round, dt_global, dt_personal = wall
+        total = dt_round + dt_global + dt_personal
         ce = np.asarray(met1.get("client_ce", []), np.float64).reshape(-1)
         gn = np.asarray(met1.get("client_grad_norm", []),
                         np.float64).reshape(-1)
@@ -238,10 +244,6 @@ class FedPipeline:
         obs.inc("fed/comm_bytes", self.comm_bytes_round, method=name,
                 comm=self.comm_class)
         obs.set_gauge("fed/loss_spread", spread, method=name)
-        for span, dt in (("fed/round", dt_round),
-                         ("fed/stage2_global", dt_global),
-                         ("fed/stage3_personalize", dt_personal)):
-            obs.observe("span_seconds", dt, span=span, method=name)
         for c in range(ce.size):
             obs.observe("fed/client_ce", float(ce[c]), method=name, client=c)
         obs.event(
@@ -460,14 +462,16 @@ def make_fed_pipeline_step(cfg: ArchConfig, mesh,
             # (not telemetry-gated) so the compiled program is identical
             # with obs on and off; equals the simulator's per-client
             # grad_norm at micro_batches=1
-            gnorm = pt.global_norm(g_acc)
-            g_acc = clip_by_global_norm(g_acc, settings.clip)
-            upd, ost_ = stage_opt.update(g_acc, ost_, ad_, step)
-            if cover is not None:
-                # heterogeneous fleet: zero the update rows above this
-                # client's rank (adapters are allocated at the server rank)
-                upd = jax.tree.map(jnp.multiply, upd, cover)
-            ad_ = apply_updates(ad_, upd)
+            with named_scope("optimizer"):
+                gnorm = pt.global_norm(g_acc)
+                g_acc = clip_by_global_norm(g_acc, settings.clip)
+                upd, ost_ = stage_opt.update(g_acc, ost_, ad_, step)
+                if cover is not None:
+                    # heterogeneous fleet: zero the update rows above this
+                    # client's rank (adapters are allocated at the server
+                    # rank)
+                    upd = jax.tree.map(jnp.multiply, upd, cover)
+                ad_ = apply_updates(ad_, upd)
             met = jax.tree.map(lambda x: jnp.sum(x, axis=0) / micro, mets)
             met = dict(met, grad_norm=gnorm)
             return (ad_, ost_, step + 1), met
@@ -522,30 +526,31 @@ def make_fed_pipeline_step(cfg: ArchConfig, mesh,
         # the post-round counter, = FedSim._step at FedSim.aggregate time.
         # ``staleness`` feeds the STALENESS (FedBuff) discount; other
         # kinds ignore it.
-        agg = collective(adapters, axes=daxes, weight=w, cover=cover,
-                         step=step0 + settings.local_steps,
-                         staleness=stale[0])
-        if settings.telemetry:
-            # per-client aggregate drift ‖client − aggregate‖ over the
-            # shared leaves, pre-rebroadcast (the simulator's
-            # _client_drift) — a per-shard scalar, all_gathered below
-            sq = jnp.zeros((), jnp.float32)
-            for (p, x), y, m in zip(
-                    jax.tree_util.tree_leaves_with_path(adapters),
-                    jax.tree.leaves(agg), jax.tree.leaves(cover)):
-                if keep_rx is not None and keep_rx.search(pt.path_str(p)):
-                    continue
-                d = x - y
-                if het:
-                    d = d * m
-                sq = sq + jnp.sum(jnp.square(d))
-            drift = jnp.sqrt(sq)
-        if zero_rx is not None:
-            agg = pt.tree_map_with_path(
-                lambda p, x: jnp.zeros_like(x) if zero_rx.search(p) else x,
-                agg)
-        out = fedagg.client_rebroadcast(agg, adapters, keep_rx,
-                                        cover if het else None)
+        with named_scope("aggregate"):
+            agg = collective(adapters, axes=daxes, weight=w, cover=cover,
+                             step=step0 + settings.local_steps,
+                             staleness=stale[0])
+            if settings.telemetry:
+                # per-client aggregate drift ‖client − aggregate‖ over the
+                # shared leaves, pre-rebroadcast (the simulator's
+                # _client_drift) — a per-shard scalar, all_gathered below
+                sq = jnp.zeros((), jnp.float32)
+                for (p, x), y, m in zip(
+                        jax.tree_util.tree_leaves_with_path(adapters),
+                        jax.tree.leaves(agg), jax.tree.leaves(cover)):
+                    if keep_rx is not None and keep_rx.search(pt.path_str(p)):
+                        continue
+                    d = x - y
+                    if het:
+                        d = d * m
+                    sq = sq + jnp.sum(jnp.square(d))
+                drift = jnp.sqrt(sq)
+            if zero_rx is not None:
+                agg = pt.tree_map_with_path(
+                    lambda p, x: jnp.zeros_like(x) if zero_rx.search(p) else x,
+                    agg)
+            out = fedagg.client_rebroadcast(agg, adapters, keep_rx,
+                                            cover if het else None)
         met_last = jax.tree.map(lambda m: jax.lax.pmean(m, daxes), mets)
         if settings.telemetry:
             # per-client metric leaves, replicated by the all_gather so

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.obs.tracing import scoped
+
 Params = Any
 
 
@@ -102,6 +104,7 @@ def apply_mrope(x, positions3, theta: float = 1e4,
 # adapter-aware linear
 # ---------------------------------------------------------------------------
 
+@scoped("lora")
 def lora_delta(p: Params, x, scale: float, dropout_rng=None,
                dropout: float = 0.0):
     """Low-rank adapter contribution for input x (..., d_in)."""
@@ -269,6 +272,7 @@ def _sdpa_chunked(q, k, v, softmax_scale, window, causal, q_block: int = 512):
     return outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, dh)
 
 
+@scoped("attn")
 def attention(p: Params, x, positions, cfg, *, kind: str = "global",
               causal: bool = True, cache=None, cache_index=None,
               kv_source=None, lora_scale: float = 0.0, dropout_rng=None,
@@ -393,6 +397,7 @@ def init_attn_cache(cfg, batch: int, seq_len: int, kind: str, dtype):
 # dense FFN (SwiGLU)
 # ---------------------------------------------------------------------------
 
+@scoped("ffn")
 def dense_ffn(p: Params, x, cfg, lora_scale: float = 0.0, adapter_idx=None):
     g = linear(p["gate_proj"], x,
                lora_scale=lora_scale if "gate_proj" in cfg.lora_targets else 0.0,

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.models import layers as L
 from repro.models import ssm as S
 from repro.models.config import ArchConfig, SubLayer
+from repro.obs.tracing import scoped
 
 Params = Any
 
@@ -385,6 +386,7 @@ def loss_and_metrics(params, batch, cfg, *, rng=None, mesh=None,
     # gemma3 train_4k), and remat keeps only the (B,Sc,D) chunk inputs as
     # residuals, recomputing logits in the backward sweep.
     @jax.checkpoint
+    @scoped("ce")
     def _ce_chunk(kern, hb, tb, mb):
         logits = hb @ kern.astype(hb.dtype)
         lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)

@@ -2,13 +2,13 @@
 
 Three layers, all safe to leave in production call sites:
 
-* ``span(name, **labels)`` — host wall-clock context manager.  When
-  telemetry is enabled it records the elapsed seconds into the
-  ``span_seconds`` histogram (label ``span=<name>`` plus any extras)
-  and opens a ``jax.profiler.TraceAnnotation`` so the region shows up
-  named in a captured trace.  When disabled it degrades to a bare
-  ``yield`` — no clock reads, no annotation, no allocation beyond the
-  generator frame.
+* ``span(name, **labels)`` — a named host region.  It always opens a
+  ``jax.profiler.TraceAnnotation(name)``, so a captured trace shows the
+  region on the device trace's clock (with no profiler running that is
+  one inactive TraceMe check).  Only while telemetry is enabled does it
+  read the clock: it records the elapsed seconds into the
+  ``span_seconds`` histogram (label ``span=<name>`` plus any extras) and
+  leaves them on the yielded ``Span``'s ``seconds``.
 
   ``span`` does NOT block on device work: callers that want the span to
   cover device execution must ``block_until_ready`` inside the span
@@ -20,12 +20,16 @@ Three layers, all safe to leave in production call sites:
   when the profiler API is unavailable.
 
 * ``named_scope(name)`` — re-export of ``jax.named_scope`` for naming
-  *operations inside* a jitted program (BGMV, quant matmul); metadata
-  only, never changes the compiled computation.
+  *operations inside* a jitted program (the model's ``attn``, ``ffn``,
+  ``lora`` and ``ce``, the stage programs' ``optimizer`` and
+  ``aggregate``, the BGMV and quant-matmul kernels); metadata only,
+  never changes the compiled computation.  ``scoped(name)`` is its
+  decorator form, with a fresh scope per call.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 
 try:  # pure-host fallback when no profiler is built in (CPU-only jax
@@ -53,22 +57,46 @@ def annotate(name: str):
     return deco
 
 
+def scoped(name: str):
+    """Decorator: run ``fn`` under ``named_scope(name)``.  One scope is
+    made per call: a ``jax.named_scope`` object used as the decorator
+    itself keeps its saved name stack on the shared object, which two
+    threads tracing at once would overwrite."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def call(*args, **kw):
+            with named_scope(name):
+                return fn(*args, **kw)
+        return call
+    return deco
+
+
+class Span:
+    """What ``span`` yields: ``seconds`` is the region's host wall time
+    once it has closed, 0.0 while telemetry is off."""
+    __slots__ = ("seconds",)
+
+    def __init__(self):
+        self.seconds = 0.0
+
+
 @contextlib.contextmanager
 def span(name: str, **labels):
-    """Time a host-side region into the ``span_seconds`` histogram."""
+    """Name a host region in profiler traces; with telemetry enabled,
+    also time it into the ``span_seconds`` histogram."""
     import repro.obs as _obs  # late: repro.obs imports this module
-    if not _obs.enabled():
-        yield
-        return
-    tel = _obs.active()
-    ann = _TraceAnnotation(name) if _TraceAnnotation is not None else None
-    if ann is not None:
-        ann.__enter__()
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        if ann is not None:
-            ann.__exit__(None, None, None)
-        tel.metrics.histogram("span_seconds").observe(dt, span=name, **labels)
+    s = Span()
+    ann = (_TraceAnnotation(name) if _TraceAnnotation is not None
+           else contextlib.nullcontext())
+    with ann:
+        if not _obs.enabled():
+            yield s
+            return
+        tel = _obs.active()
+        t0 = time.perf_counter()
+        try:
+            yield s
+        finally:
+            s.seconds = time.perf_counter() - t0
+            tel.metrics.histogram("span_seconds").observe(
+                s.seconds, span=name, **labels)
