@@ -24,13 +24,15 @@ B_mag:(r,).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
+from repro import obs
 from repro.obs.tracing import scoped
 
 Params = Any
@@ -60,19 +62,27 @@ def _rope_freqs(dh: int, theta: float):
     return theta ** (-jnp.arange(0, dh // 2, dtype=jnp.float32) / (dh // 2))
 
 
-def apply_rope(x, positions, theta: float = 1e4):
+def _rotate(x, cos, sin, scale):
+    """Rotate in float32; ``scale`` (the softmax scale the flash kernel
+    does not apply) is folded in before the one cast back to x's dtype."""
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    if scale is not None:
+        out = out * scale
+    return out.astype(x.dtype)
+
+
+def apply_rope(x, positions, theta: float = 1e4, scale=None):
     """x: (B, S, H, dh); positions: (B, S) int32."""
     dh = x.shape[-1]
     freqs = _rope_freqs(dh, theta)                       # (dh/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B,S,dh/2)
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
+    return _rotate(x, cos, sin, scale)
 
 
 def apply_mrope(x, positions3, theta: float = 1e4,
-                sections=(0.25, 0.375, 0.375)):
+                sections=(0.25, 0.375, 0.375), scale=None):
     """Qwen2-VL multimodal rotary: positions3 (B, S, 3) = (t, h, w) ids.
 
     The dh/2 frequency bands are split into three sections, each rotated by
@@ -95,9 +105,7 @@ def apply_mrope(x, positions3, theta: float = 1e4,
         + sel[None, None, :], axis=-1)                    # (B,S,dh/2)
     ang = pos * freqs
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
+    return _rotate(x, cos, sin, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -272,12 +280,88 @@ def _sdpa_chunked(q, k, v, softmax_scale, window, causal, q_block: int = 512):
     return outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, dh)
 
 
+def _flash_impl() -> str:
+    """The flash kernel's implementation in this process: compiled on a
+    TPU, "einsum" (the chunked XLA path) elsewhere (``kernels.dispatch``)."""
+    from repro.kernels.dispatch import resolve_impl
+    return resolve_impl(None, "flash_attention_causal")
+
+
+_ROW_AXES = ("pod", "data")        # mesh axes that split batch rows
+
+
+def _unmapped_axes() -> dict[str, int]:
+    """The context mesh's axes this code is not manual over, with their
+    sizes: GSPMD partitions the arrays over them (empty with no mesh)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return {a: n for a, n, t in zip(mesh.axis_names, mesh.axis_sizes,
+                                    mesh.axis_types)
+            if t != AxisType.Manual}
+
+
+def attention_path(*, causal: bool, cross: bool, S: int, dh: int, H: int,
+                   K: int, impl: str, B: int = 1,
+                   mesh_axes: dict[str, int] | None = None
+                   ) -> tuple[str, str]:
+    """Which core runs a training/prefill attention call, and the first
+    condition that kept it off the flash kernel ("none" when it runs).
+
+      dense    one (S × S) softmax (``_sdpa``): short sequences
+      chunked  ``_sdpa_chunked``'s query blocks: calls the kernel refuses,
+               and every call where no TPU runs it (impl "einsum")
+      flash    ``kernels.flash_attention_causal`` (sliding windows included;
+               its block, ``flash_block``, divides any S kept here)
+
+    ``mesh_axes`` are the mesh axes GSPMD partitions the call over
+    (``_unmapped_axes``).  A Pallas kernel has no partitioning rule, so
+    the flash path runs it per shard (``_flash``): batch rows over the
+    data axes, heads over 'model' (the axes ``launch/mesh`` builds).  It
+    refuses a layout those shards cannot hold.
+    """
+    mesh_axes = mesh_axes or {}
+    rows = math.prod(n for a, n in mesh_axes.items() if a in _ROW_AXES)
+    heads = mesh_axes.get("model", 1)
+    if S < 2048:
+        return "dense", "short"
+    if S % 512:
+        return "dense", "seq_len % 512"
+    for why, refused in (("cross-attention", cross),
+                         ("non-causal", not causal),
+                         ("head_dim % 128", dh % 128),
+                         ("heads % kv_heads", H % K),
+                         ("no TPU", impl == "einsum"),
+                         ("kv_heads % model", K % heads),
+                         ("batch % data", B % rows)):
+        if refused:
+            return "chunked", why
+    return "flash", "none"
+
+
+def _flash(q, k, v, window, impl, mesh_axes):
+    """The flash kernel on (B,S,H,dh) q and (B,S,K,dh) k/v.  Under a mesh
+    it runs per shard, in a shard_map over ``mesh_axes``: batch rows over
+    the data axes, query and key heads over 'model'.  The shard_map names
+    the axes already manual too: the kernel lowers only where every axis
+    is manual, and a nested shard_map under ``jax.set_mesh`` counts only
+    the axes it names."""
+    from repro.kernels import flash_attention_causal
+    run = functools.partial(flash_attention_causal, window=window, impl=impl)
+    if not mesh_axes:
+        return run(q, k, v)
+    mesh = jax.sharding.get_abstract_mesh()
+    rows = tuple(a for a in mesh_axes if a in _ROW_AXES) or None
+    spec = P(rows, None, "model" if "model" in mesh_axes else None, None)
+    return jax.shard_map(run, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, axis_names=set(mesh.axis_names),
+                         check_vma=False)(q, k, v)
+
+
 @scoped("attn")
 def attention(p: Params, x, positions, cfg, *, kind: str = "global",
               causal: bool = True, cache=None, cache_index=None,
               kv_source=None, lora_scale: float = 0.0, dropout_rng=None,
-              chunk_q: bool = False, return_cache: bool = False,
-              cache_len: int = 0, adapter_idx=None):
+              return_cache: bool = False, cache_len: int = 0,
+              adapter_idx=None):
     """Full attention sublayer (pre-norm outside).  Returns (y, new_cache).
 
     cache: dict(k=(B,Sc,K,dh), v=...) — decode ring/linear buffer.
@@ -317,15 +401,25 @@ def attention(p: Params, x, positions, cfg, *, kind: str = "global",
         q = head_rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = head_rms_norm(k, p["k_norm"], cfg.norm_eps)
 
+    path = None
+    if cache is None:                                      # train / prefill
+        impl, mesh_axes = _flash_impl(), _unmapped_axes()
+        path, why = attention_path(causal=causal, cross=kv_source is not None,
+                                   S=S, dh=dh, H=H, K=Kh, impl=impl, B=B,
+                                   mesh_axes=mesh_axes)
+        obs.inc("attn_path", path=path, why=why)
+    # the kernel applies no softmax scale: q carries it out of the rope
+    q_scale = scale if path == "flash" else None
+
     if kv_source is None:                                  # self-attn: rope
         if cfg.mrope:
             pos3 = positions if positions.ndim == 3 else jnp.repeat(
                 positions[..., None], 3, axis=-1)
-            q = apply_mrope(q, pos3, cfg.rope_theta)
+            q = apply_mrope(q, pos3, cfg.rope_theta, scale=q_scale)
             k = apply_mrope(k, pos3, cfg.rope_theta)
         else:
             pos = positions if positions.ndim == 2 else positions[..., 0]
-            q = apply_rope(q, pos, cfg.rope_theta)
+            q = apply_rope(q, pos, cfg.rope_theta, scale=q_scale)
             k = apply_rope(k, pos, cfg.rope_theta)
 
     new_cache = None
@@ -357,7 +451,9 @@ def attention(p: Params, x, positions, cfg, *, kind: str = "global",
         out = _sdpa(q, k, v, None, scale)
         new_cache = cache
     else:
-        if chunk_q and S >= 2048 and S % 512 == 0:
+        if path == "flash":
+            out = _flash(q, k, v, window, impl, mesh_axes)
+        elif path == "chunked":
             out = _sdpa_chunked(q, k, v, scale, window, causal)
         else:
             mask = None

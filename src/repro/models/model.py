@@ -157,8 +157,7 @@ def init_params(rng, cfg: ArchConfig) -> Params:
 def _apply_sublayer(p, x, sub: SubLayer, cfg, *, positions, cache=None,
                     cache_index=None, enc_out=None, lora_scale=0.0,
                     dropout_rng=None, mesh=None, causal=True,
-                    chunk_q=False, return_cache=False, cache_len=0,
-                    adapter_idx=None):
+                    return_cache=False, cache_len=0, adapter_idx=None):
     aux = jnp.zeros((), jnp.float32)
     new_cache = {}
     h = L.rms_norm(x, p["input_norm"], cfg.norm_eps)
@@ -169,7 +168,7 @@ def _apply_sublayer(p, x, sub: SubLayer, cfg, *, positions, cache=None,
             p["attn"], h, positions, cfg, kind=sub.attn_kind,
             causal=causal and sub.mixer != "cross_attn",
             cache=acache, cache_index=cache_index, kv_source=kv_src,
-            lora_scale=lora_scale, dropout_rng=dropout_rng, chunk_q=chunk_q,
+            lora_scale=lora_scale, dropout_rng=dropout_rng,
             return_cache=return_cache, cache_len=cache_len,
             adapter_idx=adapter_idx)
         if nc is not None:
@@ -202,9 +201,8 @@ def _apply_sublayer(p, x, sub: SubLayer, cfg, *, positions, cache=None,
     return x, new_cache, aux
 
 
-def _superblock_fn(pattern, cfg, *, causal=True, mesh=None, chunk_q=False,
-                   remat=False, return_cache=False, cache_len=0,
-                   adapter_idx=None):
+def _superblock_fn(pattern, cfg, *, causal=True, mesh=None, remat=False,
+                   return_cache=False, cache_len=0, adapter_idx=None):
     """Returns body(x, p_sb, cache_sb, positions, cache_index, enc_out, rng)."""
 
     def body(x, p_sb, cache_sb, positions, cache_index, enc_out, rng):
@@ -221,8 +219,8 @@ def _superblock_fn(pattern, cfg, *, causal=True, mesh=None, chunk_q=False,
                 p_sb[key], x, sub, cfg, positions=positions, cache=c,
                 cache_index=cache_index, enc_out=enc_out,
                 lora_scale=scale, dropout_rng=r, mesh=mesh, causal=causal,
-                chunk_q=chunk_q, return_cache=return_cache,
-                cache_len=cache_len, adapter_idx=adapter_idx)
+                return_cache=return_cache, cache_len=cache_len,
+                adapter_idx=adapter_idx)
             if nc:
                 new_cache[key] = nc
             aux = aux + a
@@ -244,12 +242,12 @@ def _superblock_fn(pattern, cfg, *, causal=True, mesh=None, chunk_q=False,
 
 def _run_blocks(blocks, tail, x, pattern, cfg, *, positions, cache=None,
                 cache_index=None, enc_out=None, rng=None, mesh=None,
-                causal=True, chunk_q=False, remat=False, return_cache=False,
+                causal=True, remat=False, return_cache=False,
                 cache_len=0, adapter_idx=None):
     """Scan over stacked superblocks, then unrolled tail."""
     body = _superblock_fn(pattern, cfg, causal=causal, mesh=mesh,
-                          chunk_q=chunk_q, remat=remat,
-                          return_cache=return_cache, cache_len=cache_len,
+                          remat=remat, return_cache=return_cache,
+                          cache_len=cache_len,
                           adapter_idx=adapter_idx)
     n_sb = 0
     if blocks:
@@ -328,7 +326,7 @@ def forward(params, batch, cfg: ArchConfig, *, rng=None, mesh=None,
         enc_out, _, _ = _run_blocks(
             params["encoder"]["blocks"], {}, enc_tokens_emb.astype(x.dtype),
             enc_pat, cfg, positions=e_pos, rng=enc_rng, mesh=mesh,
-            causal=False, chunk_q=True, remat=remat)
+            causal=False, remat=remat)
         enc_out = L.rms_norm(enc_out, params["encoder"]["final_norm"],
                              cfg.norm_eps)
 
@@ -340,7 +338,7 @@ def forward(params, batch, cfg: ArchConfig, *, rng=None, mesh=None,
     x, cache, aux = _run_blocks(
         params["blocks"], params.get("tail", {}), x, pattern, cfg,
         positions=positions, enc_out=enc_out, rng=rng, mesh=mesh,
-        causal=causal, chunk_q=True, remat=remat, return_cache=return_cache,
+        causal=causal, remat=remat, return_cache=return_cache,
         cache_len=cache_len, adapter_idx=batch.get("adapter_idx"))
 
     if "prompt_embed" in params:

@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import (attention_ref, flash_attention, fused_dora,
+from repro.kernels import (attention_ref, flash_attention,
+                           flash_attention_causal, fused_dora,
                            fused_dora_ref, ssd_naive, ssd_ref, ssd_scan)
 
 RNG = np.random.default_rng(7)
@@ -181,6 +182,8 @@ def _explicit_compiled_calls():
         "quant_matmul": lambda: quant_matmul(x3, q, s, impl="pallas"),
         "flash_attention": lambda: flash_attention(qkv, qkv, qkv,
                                                    interpret=False),
+        "flash_attention_causal": lambda: flash_attention_causal(
+            qkv, qkv, qkv, impl="pallas"),
         "ssd_scan": lambda: ssd_scan(*ssd_in, chunk=16, interpret=False),
         "fused_dora": lambda: fused_dora(
             jnp.ones((128, 128)), w0, jnp.ones((128, 4)), jnp.ones((128,)),
@@ -189,7 +192,8 @@ def _explicit_compiled_calls():
 
 
 @pytest.mark.parametrize("op", ["bgmv", "bgmv_mag", "quant_matmul",
-                                "flash_attention", "ssd_scan", "fused_dora"])
+                                "flash_attention", "flash_attention_causal",
+                                "ssd_scan", "fused_dora"])
 def test_explicit_compiled_kernel_refuses_non_tpu(op, monkeypatch):
     """Asking for the compiled kernel off-TPU raises — it never quietly
     runs the Pallas interpreter in its place.  The backend probe is
