@@ -453,9 +453,10 @@ def make_fed_pipeline_step(cfg: ArchConfig, mesh,
             (g_acc, n_tot), mets = jax.lax.scan(
                 acc_body, (g0, jnp.zeros((), jnp.float32)), sb)
             if grad_axes is not None:
-                n_tot = jax.lax.psum(n_tot, grad_axes)
-                g_acc = jax.tree.map(
-                    lambda x: jax.lax.psum(x, grad_axes) / n_tot, g_acc)
+                with named_scope("grad_sync"):
+                    n_tot = jax.lax.psum(n_tot, grad_axes)
+                    g_acc = jax.tree.map(
+                        lambda x: jax.lax.psum(x, grad_axes) / n_tot, g_acc)
             else:
                 g_acc = jax.tree.map(lambda x: x / micro, g_acc)
             # pre-clip gradient norm rides the metrics unconditionally

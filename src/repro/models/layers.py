@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import AxisType, PartitionSpec as P
 
 from repro import obs
-from repro.obs.tracing import scoped
+from repro.obs.tracing import named_scope, scoped
 
 Params = Any
 
@@ -515,6 +515,7 @@ def dense_ffn(p: Params, x, cfg, lora_scale: float = 0.0, adapter_idx=None):
 # MoE FFN — sort+capacity grouped matmul, optional expert-parallel a2a
 # ---------------------------------------------------------------------------
 
+@scoped("moe_dispatch")
 def _group_by_expert(xt, top_i, top_w, E_slots: int, C: int, fsplit: int):
     """Token grouping: returns (xg (E_slots*C, D), combine info).
 
@@ -543,6 +544,7 @@ def _group_by_expert(xt, top_i, top_w, E_slots: int, C: int, fsplit: int):
     return xg[:-1], (st, sw, dest, keep)
 
 
+@scoped("moe_combine")
 def _combine_from_expert(yg, combine, T: int):
     st, sw, dest, keep = combine
     D = yg.shape[-1]
@@ -551,6 +553,7 @@ def _combine_from_expert(yg, combine, T: int):
     return jnp.zeros((T, D), yg.dtype).at[st].add(vals)
 
 
+@scoped("moe_experts")
 def _expert_mlp(xg, wg, wu, wd):
     """xg: (E_loc, C, D); weights (E_loc, D, F_loc)/(E_loc, F_loc, D)."""
     g = jnp.einsum("ecd,edf->ecf", xg, wg.astype(xg.dtype))
@@ -559,6 +562,7 @@ def _expert_mlp(xg, wg, wu, wd):
     return jnp.einsum("ecf,efd->ecd", h, wd.astype(xg.dtype))
 
 
+@scoped("moe_router")
 def moe_router(p, xt, cfg, fsplit: int):
     logits = (xt @ p["router"]["kernel"].astype(xt.dtype)).astype(jnp.float32)
     top_w, top_i = jax.lax.top_k(logits, cfg.top_k)
@@ -571,6 +575,22 @@ def moe_router(p, xt, cfg, fsplit: int):
     return top_i, top_w, aux
 
 
+def moe_capacity(cfg, T: int) -> int:
+    """Token slots per expert for T tokens: ceil(k·T·capacity_factor/E),
+    at least 1 and at most T.  An expert keeps its tokens in token order
+    until it is full; later ones lose that expert."""
+    C = math.ceil(cfg.top_k * T * cfg.capacity_factor / cfg.n_experts)
+    return min(max(1, int(C)), T)
+
+
+def _count_moe_path(path: str, experts: int, capacity: int) -> None:
+    """The ``moe_path`` counter, at trace time: which expert-layer body
+    a traced call took, the expert slots it holds and their capacity."""
+    # lint: ok[R1] counts static shapes, once per trace
+    obs.inc("moe_path", path=path, experts=experts, capacity=capacity)
+
+
+@scoped("ffn")
 def moe_ffn_local(p: Params, x, cfg):
     """Single-shard sort+capacity grouped-matmul MoE.
 
@@ -583,8 +603,8 @@ def moe_ffn_local(p: Params, x, cfg):
     xt = x.reshape(T, D)
     fsplit = cfg.ep_fsplit
     E_slots = cfg.n_experts * fsplit
-    C = max(1, int(math.ceil(cfg.top_k * T * cfg.capacity_factor / cfg.n_experts)))
-    C = min(C, T)
+    C = moe_capacity(cfg, T)
+    _count_moe_path("local", E_slots, C)
     top_i, top_w, aux = moe_router(p, xt, cfg, fsplit)
     xg, combine = _group_by_expert(xt, top_i, top_w, E_slots, C, fsplit)
     wg, wu, wd = p["experts"]["gate"], p["experts"]["up"], p["experts"]["down"]
@@ -593,6 +613,7 @@ def moe_ffn_local(p: Params, x, cfg):
     return y.reshape(B, S, D), aux
 
 
+@scoped("ffn")
 def moe_ffn_manual(p: Params, x, cfg, dp: int, ep_axis: str = "data"):
     """MoE body for code already running inside a manual region over the
     data axes (launch/train.py's client shard_map).  Tokens are per-shard;
@@ -605,21 +626,24 @@ def moe_ffn_manual(p: Params, x, cfg, dp: int, ep_axis: str = "data"):
     fsplit = cfg.ep_fsplit
     E_slots = cfg.n_experts * fsplit
     E_loc = E_slots // dp
+    C = moe_capacity(cfg, T)
+    _count_moe_path("manual", E_loc, C)
     top_i, top_w, aux = moe_router(p, xt, cfg, fsplit)
-    C = max(1, int(math.ceil(cfg.top_k * T * cfg.capacity_factor / cfg.n_experts)))
-    C = min(C, T)
     xg, combine = _group_by_expert(xt, top_i, top_w, E_slots, C, fsplit)
-    xg = xg.reshape(dp, E_loc, C, D)
-    xr = jax.lax.all_to_all(xg, ep_axis, split_axis=0, concat_axis=0)
-    xr = xr.transpose(1, 0, 2, 3).reshape(E_loc, dp * C, D)
+    with named_scope("moe_dispatch"):
+        xg = xg.reshape(dp, E_loc, C, D)
+        xr = jax.lax.all_to_all(xg, ep_axis, split_axis=0, concat_axis=0)
+        xr = xr.transpose(1, 0, 2, 3).reshape(E_loc, dp * C, D)
     wg, wu, wd = p["experts"]["gate"], p["experts"]["up"], p["experts"]["down"]
     yr = _expert_mlp(xr, wg, wu, wd)
-    yr = yr.reshape(E_loc, dp, C, D).transpose(1, 0, 2, 3)
-    yg = jax.lax.all_to_all(yr, ep_axis, split_axis=0, concat_axis=0)
+    with named_scope("moe_combine"):
+        yr = yr.reshape(E_loc, dp, C, D).transpose(1, 0, 2, 3)
+        yg = jax.lax.all_to_all(yr, ep_axis, split_axis=0, concat_axis=0)
     y = _combine_from_expert(yg.reshape(E_slots * C, D), combine, T)
     return y.reshape(B_l, S, D), aux
 
 
+@scoped("ffn")
 def moe_ffn_ep(p: Params, x, cfg, mesh, ep_axis: str = "data"):
     """Expert-parallel MoE via shard_map + all_to_all over ``ep_axis``.
 
@@ -648,22 +672,26 @@ def moe_ffn_ep(p: Params, x, cfg, mesh, ep_axis: str = "data"):
             B_l, S, D = x_l.shape
             T = B_l * S
             xt = x_l.reshape(T, D)
+            C = moe_capacity(cfg, T)
+            _count_moe_path("ep", E_loc, C)
             top_i, top_w, aux = moe_router({"router": {"kernel": router}},
                                            xt, cfg, fsplit)
-            C = max(1, int(math.ceil(
-                cfg.top_k * T * cfg.capacity_factor / cfg.n_experts)))
-            C = min(C, T)
             xg, combine = _group_by_expert(xt, top_i, top_w, E_slots, C,
                                            fsplit)
             idx = jax.lax.axis_index(ep_axis)
-            x_loc = jax.lax.dynamic_slice_in_dim(
-                xg.reshape(E_slots, C, D), idx * E_loc, E_loc, 0)
+            with named_scope("moe_dispatch"):
+                x_loc = jax.lax.dynamic_slice_in_dim(
+                    xg.reshape(E_slots, C, D), idx * E_loc, E_loc, 0)
             y_loc = _expert_mlp(x_loc, wg, wu, wd)
-            yg = jnp.zeros((E_slots, C, D), y_loc.dtype)
-            yg = jax.lax.dynamic_update_slice_in_dim(yg, y_loc, idx * E_loc, 0)
+            with named_scope("moe_combine"):
+                yg = jnp.zeros((E_slots, C, D), y_loc.dtype)
+                yg = jax.lax.dynamic_update_slice_in_dim(yg, y_loc,
+                                                         idx * E_loc, 0)
             y = _combine_from_expert(yg.reshape(E_slots * C, D), combine, T)
-            y = jax.lax.psum(y, (ep_axis, "model"))
-            aux = jax.lax.pmean(aux, batch_axes)
+            with named_scope("moe_combine"):
+                y = jax.lax.psum(y, (ep_axis, "model"))
+            with named_scope("moe_router"):
+                aux = jax.lax.pmean(aux, batch_axes)
             return y.reshape(B_l, S, D), aux
 
         out = jax.shard_map(
@@ -681,22 +709,25 @@ def moe_ffn_ep(p: Params, x, cfg, mesh, ep_axis: str = "data"):
         B_l, S, D = x_l.shape
         T = B_l * S
         xt = x_l.reshape(T, D)
+        C = moe_capacity(cfg, T)
+        _count_moe_path("ep", E_loc, C)
         top_i, top_w, aux = moe_router({"router": {"kernel": router}}, xt,
                                        cfg, fsplit)
-        C = max(1, int(math.ceil(
-            cfg.top_k * T * cfg.capacity_factor / cfg.n_experts)))
-        C = min(C, T)
         xg, combine = _group_by_expert(xt, top_i, top_w, E_slots, C, fsplit)
-        xg = xg.reshape(dp, E_loc, C, D)
-        # dispatch: swap device axis <-> slot-owner axis
-        xr = jax.lax.all_to_all(xg, ep_axis, split_axis=0, concat_axis=0)
-        xr = xr.transpose(1, 0, 2, 3).reshape(E_loc, dp * C, D)
+        with named_scope("moe_dispatch"):
+            xg = xg.reshape(dp, E_loc, C, D)
+            # dispatch: swap device axis <-> slot-owner axis
+            xr = jax.lax.all_to_all(xg, ep_axis, split_axis=0, concat_axis=0)
+            xr = xr.transpose(1, 0, 2, 3).reshape(E_loc, dp * C, D)
         yr = _expert_mlp(xr, wg, wu, wd)                   # partial over F_loc
-        yr = yr.reshape(E_loc, dp, C, D).transpose(1, 0, 2, 3)
-        yg = jax.lax.all_to_all(yr, ep_axis, split_axis=0, concat_axis=0)
+        with named_scope("moe_combine"):
+            yr = yr.reshape(E_loc, dp, C, D).transpose(1, 0, 2, 3)
+            yg = jax.lax.all_to_all(yr, ep_axis, split_axis=0, concat_axis=0)
         y = _combine_from_expert(yg.reshape(E_slots * C, D), combine, T)
-        y = jax.lax.psum(y, "model")                       # F_loc partials
-        aux = jax.lax.pmean(aux, batch_axes)
+        with named_scope("moe_combine"):
+            y = jax.lax.psum(y, "model")                   # F_loc partials
+        with named_scope("moe_router"):
+            aux = jax.lax.pmean(aux, batch_axes)
         return y.reshape(B_l, S, D), aux
 
     x_spec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0],
