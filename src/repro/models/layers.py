@@ -512,8 +512,92 @@ def dense_ffn(p: Params, x, cfg, lora_scale: float = 0.0, adapter_idx=None):
 
 
 # ---------------------------------------------------------------------------
-# MoE FFN — sort+capacity grouped matmul, optional expert-parallel a2a
+# MoE FFN — capacity-slotted grouped matmul, optional expert-parallel a2a
 # ---------------------------------------------------------------------------
+
+def _rows(a, idx):
+    """``a[idx]`` along the first axis, zero where ``idx`` is out of range
+    (the tables' sentinel for an empty slot or a dropped pair)."""
+    return a.at[idx].get(mode="fill", fill_value=0)
+
+
+def _slot_tables(top_i, E_slots: int, C: int):
+    """The capacity routing as two index tables of the T·K (token, choice)
+    pairs in token order (pair t·K + j is token t's j-th choice):
+
+    * ``pair_of_slot`` (E_slots·C,): the pair in slot e·C + p, or T·K
+      where the slot is empty;
+    * ``slot_of_pair`` (T, K): each pair's slot, or E_slots·C where the
+      pair was dropped.
+
+    A pair's place in its slot's queue counts the earlier pairs routed
+    to that slot (a cumsum over a one-hot), so each slot keeps its first C
+    pairs in token order.  No sort, and the one scatter writes distinct
+    slots: dropped pairs fall outside the table and are discarded.
+    """
+    T, K = top_i.shape
+    N = T * K
+    flat_e = top_i.reshape(N)
+    hot = (flat_e[:, None] == jnp.arange(E_slots)).astype(jnp.int32)
+    place = jnp.sum((jnp.cumsum(hot, axis=0) - hot) * hot, axis=1)
+    slot = jnp.where(place < C, flat_e * C + place, E_slots * C)
+    pair_of_slot = jnp.full((E_slots * C,), N, jnp.int32).at[slot].set(
+        jnp.arange(N, dtype=jnp.int32), mode="drop")
+    return pair_of_slot, slot.reshape(T, K)
+
+
+@jax.custom_vjp
+def _dispatch(xt, pair_of_slot, slot_of_pair):
+    """xg[s] = xt[token of slot s], zero rows for empty slots."""
+    K = slot_of_pair.shape[1]
+    return _rows(xt, pair_of_slot // K)
+
+
+def _dispatch_fwd(xt, pair_of_slot, slot_of_pair):
+    return _dispatch(xt, pair_of_slot, slot_of_pair), slot_of_pair
+
+
+def _dispatch_bwd(slot_of_pair, dxg):
+    """dxt[t] = Σ_j dxg[slot of pair (t, j)]: a gather, not a scatter-add."""
+    with named_scope("moe_dispatch"):
+        dxt = _rows(dxg, slot_of_pair).astype(jnp.float32).sum(1)
+        return dxt.astype(dxg.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(yg, w, pair_of_slot, slot_of_pair):
+    """y[t] = Σ_j w[t, j] · yg[slot of pair (t, j)], summed in float32;
+    dropped pairs read zero rows."""
+    rows = _rows(yg, slot_of_pair).astype(jnp.float32)          # (T, K, D)
+    y = jnp.sum(rows * w.astype(jnp.float32)[..., None], axis=1)
+    return y.astype(yg.dtype)
+
+
+def _combine_fwd(yg, w, pair_of_slot, slot_of_pair):
+    return (_combine(yg, w, pair_of_slot, slot_of_pair),
+            (yg, w, pair_of_slot, slot_of_pair))
+
+
+def _combine_bwd(res, dy):
+    """dyg[s] = w(s) · dy[token of s] (zero for empty slots) and
+    dw[t, j] = ⟨dy[t], yg[slot of (t, j)]⟩ (zero for dropped pairs):
+    gathers through the same two tables."""
+    yg, w, pair_of_slot, slot_of_pair = res
+    K = w.shape[1]
+    with named_scope("moe_combine"):
+        dy32 = dy.astype(jnp.float32)
+        w_slot = _rows(w.reshape(-1), pair_of_slot).astype(jnp.float32)
+        dyg = _rows(dy32, pair_of_slot // K) * w_slot[:, None]
+        rows = _rows(yg, slot_of_pair).astype(jnp.float32)
+        dw = jnp.sum(rows * dy32[:, None, :], axis=-1)
+        return dyg.astype(yg.dtype), dw.astype(w.dtype), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
 
 @scoped("moe_dispatch")
 def _group_by_expert(xt, top_i, top_w, E_slots: int, C: int, fsplit: int):
@@ -522,35 +606,23 @@ def _group_by_expert(xt, top_i, top_w, E_slots: int, C: int, fsplit: int):
     Tokens routed to logical expert e are duplicated onto the fsplit
     physical slots [e*fsplit, (e+1)*fsplit) — each slot holds a 1/fsplit
     slice of d_ff, and the down-projection partial sums recombine in the
-    weighted scatter-add (expert tensor-parallel trick for E < EP-degree).
+    weighted combine (expert tensor-parallel trick for E < EP-degree).
+    Rows move by gathers only, forward and backward (``_dispatch``,
+    ``_combine``).
     """
     T, k = top_i.shape
     if fsplit > 1:
         top_i = (top_i[..., None] * fsplit
                  + jnp.arange(fsplit)[None, None, :]).reshape(T, k * fsplit)
         top_w = jnp.repeat(top_w, fsplit, axis=-1)
-        k = k * fsplit
-    flat_e = top_i.reshape(-1)
-    flat_t = jnp.repeat(jnp.arange(T), k)
-    flat_w = top_w.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True)
-    se, st, sw = flat_e[order], flat_t[order], flat_w[order]
-    first = jnp.searchsorted(se, se, side="left")
-    pos = jnp.arange(T * k) - first
-    keep = pos < C
-    dest = jnp.where(keep, se * C + pos, E_slots * C)      # overflow → dump row
-    xg = jnp.zeros((E_slots * C + 1, xt.shape[-1]), xt.dtype)
-    xg = xg.at[dest].add(xt[st])
-    return xg[:-1], (st, sw, dest, keep)
+    pair_of_slot, slot_of_pair = _slot_tables(top_i, E_slots, C)
+    xg = _dispatch(xt, pair_of_slot, slot_of_pair)
+    return xg, (top_w, pair_of_slot, slot_of_pair)
 
 
 @scoped("moe_combine")
-def _combine_from_expert(yg, combine, T: int):
-    st, sw, dest, keep = combine
-    D = yg.shape[-1]
-    yg1 = jnp.concatenate([yg, jnp.zeros((1, D), yg.dtype)], axis=0)
-    vals = yg1[jnp.where(keep, dest, yg.shape[0])] * (sw * keep)[:, None].astype(yg.dtype)
-    return jnp.zeros((T, D), yg.dtype).at[st].add(vals)
+def _combine_from_expert(yg, combine):
+    return _combine(yg, *combine)
 
 
 @scoped("moe_experts")
@@ -585,14 +657,16 @@ def moe_capacity(cfg, T: int) -> int:
 
 def _count_moe_path(path: str, experts: int, capacity: int) -> None:
     """The ``moe_path`` counter, at trace time: which expert-layer body
-    a traced call took, the expert slots it holds and their capacity."""
+    a traced call took, how it moves rows (gathers through the slot
+    tables), the expert slots it holds and their capacity."""
     # lint: ok[R1] counts static shapes, once per trace
-    obs.inc("moe_path", path=path, experts=experts, capacity=capacity)
+    obs.inc("moe_path", path=path, dispatch="gather", experts=experts,
+            capacity=capacity)
 
 
 @scoped("ffn")
 def moe_ffn_local(p: Params, x, cfg):
-    """Single-shard sort+capacity grouped-matmul MoE.
+    """Single-shard capacity-slotted grouped-matmul MoE.
 
     Expert weights are stored in *slot layout* ``(E·fsplit, D, F/fsplit)``
     (see ArchConfig.ep_fsplit); for fsplit == 1 this is the plain layout.
@@ -609,7 +683,7 @@ def moe_ffn_local(p: Params, x, cfg):
     xg, combine = _group_by_expert(xt, top_i, top_w, E_slots, C, fsplit)
     wg, wu, wd = p["experts"]["gate"], p["experts"]["up"], p["experts"]["down"]
     yg = _expert_mlp(xg.reshape(E_slots, C, D), wg, wu, wd).reshape(E_slots * C, D)
-    y = _combine_from_expert(yg, combine, T)
+    y = _combine_from_expert(yg, combine)
     return y.reshape(B, S, D), aux
 
 
@@ -639,7 +713,7 @@ def moe_ffn_manual(p: Params, x, cfg, dp: int, ep_axis: str = "data"):
     with named_scope("moe_combine"):
         yr = yr.reshape(E_loc, dp, C, D).transpose(1, 0, 2, 3)
         yg = jax.lax.all_to_all(yr, ep_axis, split_axis=0, concat_axis=0)
-    y = _combine_from_expert(yg.reshape(E_slots * C, D), combine, T)
+    y = _combine_from_expert(yg.reshape(E_slots * C, D), combine)
     return y.reshape(B_l, S, D), aux
 
 
@@ -687,7 +761,7 @@ def moe_ffn_ep(p: Params, x, cfg, mesh, ep_axis: str = "data"):
                 yg = jnp.zeros((E_slots, C, D), y_loc.dtype)
                 yg = jax.lax.dynamic_update_slice_in_dim(yg, y_loc,
                                                          idx * E_loc, 0)
-            y = _combine_from_expert(yg.reshape(E_slots * C, D), combine, T)
+            y = _combine_from_expert(yg.reshape(E_slots * C, D), combine)
             with named_scope("moe_combine"):
                 y = jax.lax.psum(y, (ep_axis, "model"))
             with named_scope("moe_router"):
@@ -723,7 +797,7 @@ def moe_ffn_ep(p: Params, x, cfg, mesh, ep_axis: str = "data"):
         with named_scope("moe_combine"):
             yr = yr.reshape(E_loc, dp, C, D).transpose(1, 0, 2, 3)
             yg = jax.lax.all_to_all(yr, ep_axis, split_axis=0, concat_axis=0)
-        y = _combine_from_expert(yg.reshape(E_slots * C, D), combine, T)
+        y = _combine_from_expert(yg.reshape(E_slots * C, D), combine)
         with named_scope("moe_combine"):
             y = jax.lax.psum(y, "model")                   # F_loc partials
         with named_scope("moe_router"):

@@ -589,6 +589,44 @@ print("OK")
 """)
 
 
+def test_moe_ep_gather_path_matches_scatter_oracle():
+    """Both bodies of ``moe_ffn_ep`` on a (data 4, model 2) mesh, with a
+    skewed router that overflows an expert, fsplit 1 and 2: the output,
+    the aux term and the gradients to x, the router kernel and the three
+    expert weights equal the scatter-form oracle's to float32 round-off
+    (summed over the 'model' axis).
+    The exchange body routes each data shard's rows on their own, so its
+    oracle runs per shard and averages the aux term; the small-batch body
+    routes the replicated batch whole."""
+    out = _run(f"""
+import sys
+sys.path.insert(0, {os.path.dirname(__file__)!r})
+import jax, jax.numpy as jnp
+from test_moe import (SHARDED, assert_trees_close, scatter_moe,
+                      skewed_case, value_and_grads)
+from repro.launch.mesh import make_debug_mesh
+from repro.models.layers import moe_ffn_ep
+mesh = make_debug_mesh(4, 2)
+for fsplit in (1, 2):
+    cfg, _, p, x = skewed_case(0.5, fsplit)
+    x8 = jnp.concatenate([x, x[:, ::-1], 0.5 * x, x[::-1]], 0)
+
+    def per_shard(p, x):
+        ys, auxs = zip(*(scatter_moe(p, x[2 * i:2 * i + 2], cfg)
+                         for i in range(4)))
+        return jnp.concatenate(ys, 0), jnp.mean(jnp.stack(auxs))
+
+    for xb, oracle in ((x8, per_shard),
+                       (x[:1], lambda p, x: scatter_moe(p, x, cfg))):
+        with jax.set_mesh(mesh):
+            got = value_and_grads(lambda p, x: moe_ffn_ep(p, x, cfg, mesh),
+                                  p, xb)
+        assert_trees_close(got, value_and_grads(oracle, p, xb), **SHARDED)
+    print("OK", fsplit)
+""")
+    assert out.count("OK") == 2
+
+
 def test_flash_attention_per_shard_matches_one_device():
     """The flash kernel on a (data 2, model 2) mesh runs per shard —
     batch rows over 'data', query and key heads over 'model' — and gives

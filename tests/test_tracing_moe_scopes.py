@@ -5,8 +5,11 @@
   under ``moe_router``, ``moe_dispatch``, ``moe_experts`` and
   ``moe_combine``: the compiled HLO carries each in the forward, the
   backward and the rematerialised forward.
-* The ``moe_path`` counter records, at trace time, the body taken, the
-  expert slots it holds and their capacity.
+* The expert layer moves D-wide rows by gathers only, each under
+  ``moe_dispatch`` or ``moe_combine``: no row scatter-add, forward,
+  backward or recompute.
+* The ``moe_path`` counter records, at trace time, the body taken, how
+  it moves rows, the expert slots it holds and their capacity.
 * Stage 2's token-weighted gradient psum runs under ``grad_sync`` when
   the server batch is split over the clients, and nowhere else.
 """
@@ -32,7 +35,13 @@ CFG = ArchConfig(name="moe-scope-t", family="moe", n_layers=2, d_model=32,
 PARTS = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 PROGRAMS = ("round_step", "global_step", "personal_step")
 OP_NAME = re.compile(r'op_name="([^"]*)"')
+# a gather or scatter: its element type, shape and op name
+MOVE = re.compile(r'= (\w+)\[([\d,]*)\]\S* (gather|scatter)\(.*op_name="([^"]*)"')
 CAPACITY = 20                     # ceil(2 · 32 tokens · 1.25 / 4)
+# the combine's backward reads the experts' output itself, so nothing of
+# its forward is recomputed; every other part runs again under the remat
+RECOMPUTED = {"moe_router": True, "moe_dispatch": True, "moe_experts": True,
+              "moe_combine": False}
 
 
 @pytest.fixture(autouse=True)
@@ -54,8 +63,9 @@ def _moe_path():
 
 @pytest.fixture(scope="module")
 def stages():
-    """({program: [op_name, ...]}, the moe_path counter) of the three
-    stage programs of a tiny expert model, lowered once each."""
+    """({program: [op_name, ...]}, the moe_path counter, {program:
+    [(kind, dtype, shape, op_name) of each gather and scatter]}) of the
+    three stage programs of a tiny expert model, lowered once each."""
     from repro.launch.mesh import make_client_mesh
     from repro.launch.train import TrainSettings, make_fed_pipeline_step
     sim = FedSim(CFG, FedHyper(method="fedlora_opt", n_clients=1))
@@ -76,9 +86,21 @@ def stages():
         counter = _moe_path()
     finally:
         obs.disable()
-    names = {k: OP_NAME.findall(v.compile().as_text())
-             for k, v in lows.items()}
-    return names, counter
+    texts = {k: v.compile().as_text() for k, v in lows.items()}
+    names = {k: OP_NAME.findall(t) for k, t in texts.items()}
+    moves = {k: [(m.group(3), m.group(1),
+                  tuple(int(d) for d in m.group(2).split(",") if d),
+                  m.group(4)) for m in MOVE.finditer(t)]
+             for k, t in texts.items()}
+    return names, counter, moves
+
+
+def _fwd_bwd_remat(names):
+    """The op names of the forward, the backward and the recompute."""
+    return ([n for n in names if "jvp(" in n and "transpose(" not in n],
+            [n for n in names if "transpose(" in n
+             and "rematted_computation" not in n],
+            [n for n in names if "rematted_computation" in n.split("/")])
 
 
 @pytest.mark.parametrize("program", PROGRAMS)
@@ -86,16 +108,33 @@ def stages():
 def test_expert_parts_in_forward_backward_and_remat(stages, program, part):
     names = [n for n in stages[0][program] if part in n.split("/")]
     assert all(re.search(rf"(^|/)ffn/(.*/)?{part}/", n) for n in names), part
-    fwd = [n for n in names if "jvp(" in n and "transpose(" not in n]
-    bwd = [n for n in names if "transpose(" in n
-           and "rematted_computation" not in n]
-    remat = [n for n in names if "rematted_computation" in n.split("/")]
-    assert fwd and bwd and remat, (part, len(fwd), len(bwd), len(remat))
+    fwd, bwd, remat = _fwd_bwd_remat(names)
+    assert fwd and bwd, (part, len(fwd), len(bwd))
+    assert bool(remat) == RECOMPUTED[part], (part, len(remat))
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_expert_rows_move_by_gathers_only(stages, program):
+    """Every gather of D-wide float rows inside ``ffn`` is the dispatch's
+    or the combine's, forward, backward and recompute; no scatter writes
+    D-wide rows anywhere in the program."""
+    moves = stages[2][program]
+    rows = [n for kind, dt, shape, n in moves if kind == "gather"
+            and dt.startswith("f") and len(shape) >= 2
+            and shape[-1] == CFG.d_model and "ffn" in n.split("/")]
+    parts = [set(n.split("/")) & {"moe_dispatch", "moe_combine"}
+             for n in rows]
+    assert all(parts), [n for n, p in zip(rows, parts) if not p]
+    assert all(_fwd_bwd_remat(rows)), rows
+    scattered = [(shape, n) for kind, _, shape, n in moves
+                 if kind == "scatter" and shape[-1:] == (CFG.d_model,)]
+    assert not scattered, scattered
 
 
 def test_moe_path_counts_each_stage_program_once(stages):
-    assert stages[1] == [{"labels": {"path": "manual", "experts": 4,
-                                     "capacity": CAPACITY}, "value": 3.0}]
+    assert stages[1] == [{"labels": {"path": "manual", "dispatch": "gather",
+                                     "experts": 4, "capacity": CAPACITY},
+                          "value": 3.0}]
 
 
 def test_no_grad_sync_with_one_client(stages):
@@ -123,8 +162,9 @@ def test_local_and_ep_bodies_carry_the_parts_and_count(path):
         fn = lambda p, x: L.moe_ffn_ep(p, x, CFG, mesh)
     obs.enable()
     low = jax.jit(fn).lower(p, x)
-    assert _moe_path() == [{"labels": {"path": path, "experts": 4,
-                                       "capacity": CAPACITY}, "value": 1.0}]
+    assert _moe_path() == [{"labels": {"path": path, "dispatch": "gather",
+                                       "experts": 4, "capacity": CAPACITY},
+                            "value": 1.0}]
     names = OP_NAME.findall(low.compile().as_text())
     for part in PARTS:
         assert any(re.search(rf"ffn/(.*/)?{part}/", n) for n in names), part
